@@ -3,8 +3,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
-import sys
 import time
 from typing import Callable
 
@@ -12,33 +10,6 @@ import numpy as np
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "experiments",
                        "bench")
-
-
-def rerun_with_devices(module: str, n_devices: int, row_prefix: str,
-                       smoke: bool = False, timeout: int = 3000):
-    """Re-exec a benchmark module in a subprocess with forced host devices.
-
-    Multi-rank benchmarks need ``XLA_FLAGS`` set before jax initializes;
-    when the calling process is already single-device (the ``benchmarks.run``
-    harness, pytest), the module re-runs itself here and the CSV rows
-    starting with ``row_prefix`` are parsed back as (name, us, derived).
-    """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src")]
-        + ([env["PYTHONPATH"]] if "PYTHONPATH" in env else []))
-    cmd = [sys.executable, "-m", module] + (["--smoke"] if smoke else [])
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                          timeout=timeout,
-                          cwd=os.path.join(os.path.dirname(__file__), ".."))
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rows = []
-    for line in proc.stdout.splitlines():
-        parts = line.strip().split(",")
-        if len(parts) == 3 and parts[0].startswith(row_prefix):
-            rows.append((parts[0], float(parts[1]), parts[2]))
-    return rows
 
 
 def save_json(name: str, payload) -> None:

@@ -36,7 +36,7 @@ import sys
 
 import numpy as np
 
-from .common import rerun_with_devices, save_json, time_fn
+from .common import save_json, time_fn
 
 DENSITY = 3.7          # atoms / nm^3 (water-ish NN-group density)
 RCUT = 0.6
@@ -68,10 +68,9 @@ def run(smoke: bool = False):
     from repro.dp.descriptors import DescriptorConfig
     from repro.dp.model import DPConfig, DPModel
     from repro.launch.mesh import make_dd_mesh
+    from repro.launch.runtime import require_devices
 
-    if len(jax.devices()) < N_RANKS:
-        return rerun_with_devices("benchmarks.comms_overlap", N_RANKS,
-                                  "comms_overlap", smoke=smoke, timeout=1800)
+    require_devices(N_RANKS, "benchmarks.comms_overlap")
 
     n = 512 if smoke else 4096
     boxl = float((n / DENSITY) ** (1.0 / 3.0))
@@ -164,8 +163,7 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    import os
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={N_RANKS}")
+    from repro.launch.runtime import use_cpu_devices
+    use_cpu_devices(N_RANKS)
     for name, us, derived in run(smoke="--smoke" in sys.argv[1:]):
         print(f"{name},{us:.1f},{derived}")

@@ -32,7 +32,7 @@ import sys
 
 import numpy as np
 
-from .common import rerun_with_devices, save_json, time_fn
+from .common import save_json, time_fn
 
 DENSITY = 3.7          # atoms / nm^3 (water-ish NN-group density)
 RCUT = 0.6
@@ -89,12 +89,9 @@ def run(smoke: bool = False):
     from repro.dp.descriptors import DescriptorConfig
     from repro.dp.model import DPConfig, DPModel
     from repro.launch.mesh import make_dd_mesh
+    from repro.launch.runtime import require_devices
 
-    if len(jax.devices()) < N_RANKS:
-        # jax is already initialized single-device (benchmark harness):
-        # re-exec in a subprocess with forced host devices
-        return rerun_with_devices("benchmarks.dd_reuse", N_RANKS, "dd_reuse",
-                                  smoke=smoke, timeout=1800)
+    require_devices(N_RANKS, "benchmarks.dd_reuse")
 
     n = 512 if smoke else 4096
     boxl = float((n / DENSITY) ** (1.0 / 3.0))
@@ -293,8 +290,7 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    import os
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={N_RANKS}")
+    from repro.launch.runtime import use_cpu_devices
+    use_cpu_devices(N_RANKS)
     for name, us, derived in run(smoke="--smoke" in sys.argv[1:]):
         print(f"{name},{us:.1f},{derived}")

@@ -29,7 +29,7 @@ import sys
 
 import numpy as np
 
-from .common import rerun_with_devices, save_json, time_fn
+from .common import save_json, time_fn
 
 DENSITY = 3.7
 RCUT = 0.6
@@ -45,12 +45,9 @@ def run(smoke: bool = False):
     from repro.dp.model import DPConfig, DPModel
     from repro.ensemble import make_ensemble_mesh
     from repro.launch.mesh import make_dd_mesh
+    from repro.launch.runtime import require_devices
 
-    if len(jax.devices()) < N_DEV:
-        # jax is already initialized single-device: re-exec with forced
-        # host devices
-        return rerun_with_devices("benchmarks.ensemble_throughput", N_DEV,
-                                  "ensemble", smoke=smoke)
+    require_devices(N_DEV, "benchmarks.ensemble_throughput")
 
     n = 512 if smoke else 4096
     r_values = (2, 4) if smoke else R_VALUES
@@ -136,8 +133,7 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    import os
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={N_DEV}")
+    from repro.launch.runtime import use_cpu_devices
+    use_cpu_devices(N_DEV)
     for name, us, derived in run(smoke="--smoke" in sys.argv[1:]):
         print(f"{name},{us:.1f},{derived}")

@@ -49,4 +49,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.runtime import use_cpu_devices
+    use_cpu_devices(8)   # the multi-rank modules; only the CPU backend reads it
     main()

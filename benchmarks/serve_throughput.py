@@ -37,7 +37,7 @@ import time
 
 import numpy as np
 
-from .common import rerun_with_devices, save_json
+from .common import save_json
 
 DENSITY = 3.7
 RCUT = 0.6
@@ -54,14 +54,11 @@ def run(smoke: bool = False):
     from repro.dp.model import DPConfig, DPModel
     from repro.ensemble import make_ensemble_mesh
     from repro.launch.mesh import make_dd_mesh
+    from repro.launch.runtime import require_devices
     from repro.serve import (ForceServer, ServeConfig,
                              pipeline_executor_factory)
 
-    if len(jax.devices()) < N_DEV:
-        # jax is already initialized single-device: re-exec with forced
-        # host devices
-        return rerun_with_devices("benchmarks.serve_throughput", N_DEV,
-                                  "serve", smoke=smoke)
+    require_devices(N_DEV, "benchmarks.serve_throughput")
 
     n = 512 if smoke else 2048
     clients = (1, 4) if smoke else CLIENTS
@@ -204,8 +201,7 @@ def run(smoke: bool = False):
 
 
 if __name__ == "__main__":
-    import os
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={N_DEV}")
+    from repro.launch.runtime import use_cpu_devices
+    use_cpu_devices(N_DEV)
     for name, us, derived in run(smoke="--smoke" in sys.argv[1:]):
         print(f"{name},{us:.1f},{derived}")

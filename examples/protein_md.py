@@ -3,18 +3,20 @@ multi-device DP-aided MD of a solvated protein with checkpoint/restart.
 
 This is the serving workload of the paper — every MD step performs batched
 distributed DP inference (two collectives: coordinate all-gather + force
-reduction) through the virtual-DD layer on an 8-rank mesh of forced host
-devices.
+reduction) through the virtual-DD layer on a ``--ranks`` mesh.
 
-  python examples/protein_md.py --ranks 8 --steps 30
-(sets XLA_FLAGS itself; run from the repo root)
+  python examples/protein_md.py --steps 30              # every device seen
+  JAX_PLATFORMS=cpu python examples/protein_md.py --ranks 8 --steps 30
+(run from the repo root; on the CPU ``--ranks`` sets the host device
+count, on a TPU it may not exceed the chips this process sees)
 """
 import argparse
 import os
 import sys
 
 ap = argparse.ArgumentParser()
-ap.add_argument("--ranks", type=int, default=8)
+ap.add_argument("--ranks", type=int, default=0,
+                help="dd ranks (0 = every device this process sees)")
 ap.add_argument("--steps", type=int, default=30)
 ap.add_argument("--residues", type=int, default=16)
 ap.add_argument("--force-mode", default="owner_full",
@@ -25,8 +27,6 @@ ap.add_argument("--balanced", action="store_true")
 ap.add_argument("--ckpt-dir", default=None)
 args = ap.parse_args()
 
-os.environ.setdefault(
-    "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.ranks}")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax  # noqa: E402
@@ -36,12 +36,18 @@ import numpy as np  # noqa: E402
 from repro.core import DDConfig, DeepmdForceProvider, suggest_config  # noqa: E402
 from repro.dp import DPModel, paper_dpa1_config  # noqa: E402
 from repro.launch.mesh import make_dd_mesh  # noqa: E402
+from repro.launch.runtime import (enable_compile_cache,  # noqa: E402
+                                  use_cpu_devices)
 from repro.md import (EngineConfig, MDEngine, build_solvated_protein,  # noqa: E402
                       mark_nn_group)
 from repro.md.observables import gyration_radii_axes  # noqa: E402
 
 
 def main():
+    use_cpu_devices(args.ranks)
+    enable_compile_cache()
+    args.ranks = args.ranks or len(jax.devices())
+    mesh = make_dd_mesh(args.ranks)   # fails if --ranks exceeds the devices
     system, positions, nn_idx = build_solvated_protein(args.residues)
     system = mark_nn_group(system, nn_idx)
     print(f"{system.n_atoms} atoms, DP group {len(nn_idx)}, "
@@ -50,7 +56,6 @@ def main():
     model = DPModel(paper_dpa1_config(ntypes=4, rcut=0.6, sel=32))
     params = model.init_params(jax.random.PRNGKey(0))
 
-    mesh = make_dd_mesh(args.ranks)
     dd = suggest_config(len(nn_idx), np.asarray(system.box), args.ranks,
                         0.6, nbr_capacity=48, slack=2.5,
                         balanced=args.balanced, force_mode=args.force_mode,

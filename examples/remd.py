@@ -4,11 +4,13 @@ R replicas of a solvated protein run as ONE jitted batched program —
 classical forces, DP inference and the integrator all carry a leading
 replica axis — with a temperature-ladder Metropolis exchange move at
 window boundaries.  With ``--ranks`` > 1 the DP force path additionally
-distributes over a 2-D (replica x dd) mesh of forced host devices.
+distributes over a 2-D (replica x dd) mesh of replicas * ranks devices.
 
   python examples/remd.py --replicas 4 --steps 40 --exchange-interval 5
-  python examples/remd.py --replicas 2 --ranks 4 --temp-ladder 280,340
-(run from the repo root)
+  JAX_PLATFORMS=cpu python examples/remd.py --replicas 2 --ranks 4 \
+      --temp-ladder 280,340
+(run from the repo root; on the CPU the mesh's host devices are created
+here, on a TPU the mesh may not exceed the chips this process sees)
 """
 import argparse
 import os
@@ -31,11 +33,6 @@ ap.add_argument("--steps", type=int, default=40)
 ap.add_argument("--residues", type=int, default=12)
 args = ap.parse_args()
 
-if args.ranks > 1:
-    os.environ.setdefault(
-        "XLA_FLAGS",
-        f"--xla_force_host_platform_device_count="
-        f"{args.replicas * args.ranks}")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax  # noqa: E402
@@ -47,12 +44,17 @@ from repro.dp import DPModel, paper_dpa1_config  # noqa: E402
 from repro.ensemble import (BatchedDeepmdProvider, EnsembleConfig,  # noqa: E402
                             EnsembleEngine, geometric_ladder,
                             make_ensemble_mesh)
+from repro.launch.runtime import (enable_compile_cache,  # noqa: E402
+                                  use_cpu_devices)
 from repro.md import (EngineConfig, build_solvated_protein,  # noqa: E402
                       mark_nn_group)
 
 
 def main():
     r = args.replicas
+    if args.ranks > 1:
+        use_cpu_devices(r * args.ranks)
+    enable_compile_cache()
     temps = (tuple(float(t) for t in args.temp_ladder.split(","))
              if args.temp_ladder else geometric_ladder(args.tmin, args.tmax, r))
     if len(temps) != r:

@@ -1,9 +1,8 @@
-"""Version shims for the JAX API surface this repo targets.
+"""The two JAX mesh entry points this repo uses, with its fixed settings.
 
-The code is written against the modern names (``jax.shard_map``,
-``jax.sharding.AxisType``); the containers/CI images pin older 0.4.x
-jaxlibs where those live under experimental modules or do not exist.
-Everything version-sensitive goes through here so call sites stay clean.
+``make_mesh`` gives every axis the Auto axis type, and ``shard_map`` turns
+off the varying-manual-axes check (the per-rank bodies mix replicated and
+sharded values freely).
 """
 
 from __future__ import annotations
@@ -11,19 +10,14 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    if hasattr(jax.sharding, "AxisType"):
-        types = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
-    return jax.make_mesh(axis_shapes, axis_names)
+def make_mesh(axis_shapes, axis_names, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with explicit Auto axis types."""
+    types = (jax.sharding.AxisType.Auto,) * len(axis_names)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=types,
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (check_vma) -> experimental shard_map (check_rep)."""
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, check_vma=False, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, check_rep=False, **kw)
+    """``jax.shard_map`` with ``check_vma`` off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

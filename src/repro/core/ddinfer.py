@@ -112,7 +112,7 @@ class DDConfig:
         if self.use_pallas and self.k_eval > 128:
             raise ValueError(
                 f"k_eval {self.k_eval} > 128 with use_pallas: the fused "
-                "neighbor-attention kernel keeps the (heads, K, K) score "
+                "neighbor-attention kernel keeps each head's (K, K) score "
                 "tile VMEM-resident with K padded to 128 lanes — cap "
                 "nbr_capacity_eval at 128 or disable use_pallas")
         if self.overlap and self.force_mode != "owner_full":
@@ -269,15 +269,25 @@ def _max_cell_occupancy(coords, box, dims: tuple[int, int, int]) -> int:
     return int(_cell_counts(coords, box, dims).max())
 
 
-def _max_shifted_cell_occupancy(coords, box, edge: float) -> int:
-    """Upper bound on atoms inside an ``edge``-sized cube at *any* origin
-    (the subdomain grid is anchored at lo - halo, not at 0): such a cube
-    spans at most 2 cells per axis of the box-anchored grid (cell width
-    >= edge), so the max wrapped 2x2x2 block sum bounds it."""
-    counts = _cell_counts(coords, box, cellmod.grid_dims(box, edge))
-    pooled = sum(np.roll(counts, (-dx, -dy, -dz), axis=(0, 1, 2))
-                 for dx in (0, 1) for dy in (0, 1) for dz in (0, 1))
-    return int(pooled.max())
+def _max_subcell_occupancy(coords, box, vgrid: VirtualGrid, halo: float,
+                           dims: tuple[int, int, int], edge: float) -> int:
+    """Exact max atoms per cell of the subdomain buffer grids — host-side,
+    config time only.  Bins every rank's buffer (its atoms plus the periodic
+    images inside the ``halo``-expanded bounds) into the open grid the
+    runtime builds: edge ``edge`` anchored at ``lo - halo``."""
+    pos = (np.asarray(coords, np.float64)[None, :, :]
+           + (IMAGE_SHIFTS * np.asarray(box, np.float64))[:, None, :])
+    pos = pos.reshape(-1, 3)
+    occ = 0
+    for r in range(int(np.prod(dims))):
+        lo, hi = vgrid.bounds(jnp.asarray(r))
+        lo = np.asarray(lo, np.float64) - halo
+        hi = np.asarray(hi, np.float64) + halo
+        inside = pos[((pos >= lo) & (pos < hi)).all(-1)]
+        frac = np.floor((inside - lo) / edge).astype(np.int64)
+        _, counts = np.unique(frac, axis=0, return_counts=True)
+        occ = max(occ, int(counts.max(initial=0)))
+    return occ
 
 
 def suggest_config(n_atoms: int, box, n_ranks: int, rcut: float,
@@ -356,13 +366,18 @@ def suggest_config(n_atoms: int, box, n_ranks: int, rcut: float,
     subcell_dims = tuple(
         int(np.ceil((max_sub[a] + 2 * halo_eff) / r_list)) + 1
         for a in range(3))
-    subcell_cap = cellmod.suggest_cell_capacity(density, r_list ** 3,
-                                                slack=max(slack, 2.0))
-    if coords is not None:
-        # rigorous bound for the shifted-origin subdomain grid; the 1.25
-        # margin absorbs MD drift (the bound itself is already conservative)
-        subcell_cap = max(subcell_cap, int(np.ceil(
-            1.25 * _max_shifted_cell_occupancy(coords, box, r_list))))
+    if coords is None:
+        subcell_cap = cellmod.suggest_cell_capacity(density, r_list ** 3,
+                                                    slack=max(slack, 2.0))
+    else:
+        # the occupancy the runtime will actually bin, plus a margin for MD
+        # drift; an undersized cell raises the overflow flag (grow + replay)
+        subcell_cap = int(np.ceil(1.25 * _max_subcell_occupancy(
+            coords, box, vgrid, halo_eff, dims, r_list))) + 8
+    # buffer rows in whole sublane tiles of 8: the kernels' atom blocks
+    # divide the buffer exactly, and the TPU compiler takes minutes over a
+    # model step whose row count needs padding to one
+    local_cap, ghost_cap = (-(-c // 8) * 8 for c in (local_cap, ghost_cap))
     return DDConfig(grid_dims=dims, local_capacity=local_cap,
                     ghost_capacity=ghost_cap, nbr_capacity=nbr_capacity,
                     halo=halo, balanced=balanced, rebalance=rebalance,
@@ -383,8 +398,7 @@ def _subdomain_nbr_list(buf_coords: jax.Array, buf_mask: jax.Array,
     """Full neighbor list inside a subdomain buffer (open boundaries —
     periodic images are explicit entries)."""
     c = buf_coords.shape[0]
-    dr = buf_coords[None, :, :] - buf_coords[:, None, :]
-    d2 = (dr ** 2).sum(-1)
+    d2 = sum((x[None, :] - x[:, None]) ** 2 for x in buf_coords.T)
     within = (d2 < rcut ** 2) & ~jnp.eye(c, dtype=bool)
     within &= (buf_mask[:, None] > 0) & (buf_mask[None, :] > 0)
     score = jnp.where(within, -jnp.arange(c, dtype=jnp.float32)[None, :], -jnp.inf)
@@ -426,11 +440,12 @@ def _subdomain_nbr_list_cells(buf_coords: jax.Array, buf_mask: jax.Array,
 
     cand = cellmod.neighborhood_candidates(table, frac, periodic=False)
     safe = jnp.where(cand >= 0, cand, 0)
-    cand_pos = buf_coords[safe]                      # (C, 27cap, 3)
-    dr = cand_pos - buf_coords[:, None, :]
+    # SoA (C, 27cap) displacement planes: a (C, 27cap, 3) array would pad
+    # its minor axis of 3 to 128 lanes on a TPU
+    dx, dy, dz = (x[safe] - x[:, None] for x in buf_coords.T)
     valid = ((cand >= 0) & (cand != jnp.arange(c)[:, None])
              & (buf_mask[:, None] > 0)).astype(buf_coords.dtype)
-    within = cell_filter_op(dr[..., 0], dr[..., 1], dr[..., 2], valid, rcut,
+    within = cell_filter_op(dx, dy, dz, valid, rcut,
                             use_pallas=use_pallas) > 0
 
     score = jnp.where(within, -cand.astype(jnp.float32), -jnp.inf)

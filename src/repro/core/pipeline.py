@@ -218,8 +218,7 @@ def _refilter_compact(buf_coords, nbr_idx, nbr_mask, cfg: DDConfig,
     zeroed tail, trimmed to ``k_eval`` — the model input then depends only
     on the *within-cutoff* pair set, so a stale list gives bitwise-identical
     forces to a fresh one, and the model tensors stay at the unskinned K."""
-    dr = buf_coords[nbr_idx] - buf_coords[:, None, :]
-    d2 = (dr ** 2).sum(-1)
+    d2 = sum((x[nbr_idx] - x[:, None]) ** 2 for x in buf_coords.T)
     mask = nbr_mask * (d2 < rcut ** 2)
     k_eval = min(cfg.k_eval, nbr_idx.shape[1])
     trim_overflow = ((mask > 0).sum(1) > k_eval).any()

@@ -18,4 +18,9 @@ def make_ensemble_mesh(n_replica_shards: int, n_dd: int,
     replica group.  ``(1, n_dd)`` batches all replicas onto every device
     group (pure vmap batching, one fused collective pair per step)."""
     from .. import compat
-    return compat.make_mesh((n_replica_shards, n_dd), (replica_axis, "dd"))
+    from ..launch.runtime import require_devices
+    n = n_replica_shards * n_dd
+    devices = require_devices(n, f"make_ensemble_mesh({n_replica_shards}, "
+                                 f"{n_dd})")
+    return compat.make_mesh((n_replica_shards, n_dd), (replica_axis, "dd"),
+                            devices=devices)

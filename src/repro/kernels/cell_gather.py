@@ -61,5 +61,6 @@ def cell_filter(dx: jax.Array, dy: jax.Array, dz: jax.Array,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((np_, mp), dx.dtype),
         interpret=interpret,
+        name="cell_filter",
     )(dx, dy, dz, valid)
     return out[:n] if pad_n else out

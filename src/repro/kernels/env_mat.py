@@ -150,6 +150,7 @@ def _env_mat_call(dx, dy, dz, mask, rcut_smth: float, rcut: float,
         out_specs=[spec] * 4,
         out_shape=out_shape,
         interpret=interpret,
+        name="env_mat_fwd",
     )(dx, dy, dz, mask)
     return tuple(o[:n] for o in outs) if np_ != n else tuple(outs)
 
@@ -171,6 +172,7 @@ def _env_mat_bwd_call(dx, dy, dz, mask, gs, gsx, gsy, gsz,
         out_specs=[spec] * 3,
         out_shape=out_shape,
         interpret=interpret,
+        name="env_mat_bwd",
     )(*arrays)
     return tuple(o[:n] for o in outs) if np_ != n else tuple(outs)
 

@@ -39,12 +39,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, seq_k: int,
 
     def body(j, carry):
         acc, m_i, l_i = carry
-        # size-1 dslice instead of a bare int index: older pallas interpret
-        # discharge rules only accept Slice/array indexers
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(j * block_k, block_k),
-                            slice(None)))[0]   # (block_k, d)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(j * block_k, block_k),
-                            slice(None)))[0]
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]   # (block_k, d)
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
         s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T) * scale
         if softcap > 0:
             s = softcap * jnp.tanh(s / softcap)

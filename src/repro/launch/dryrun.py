@@ -271,6 +271,8 @@ def main():
     ap.add_argument("--expert-2d", action="store_true")
     ap.add_argument("--flash-decode", action="store_true")
     args = ap.parse_args()
+    from .runtime import enable_compile_cache
+    enable_compile_cache()
 
     if args.flash_decode:
         from ..lm.layers import set_flash_decode

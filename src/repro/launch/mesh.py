@@ -7,6 +7,7 @@ devices while tests/benches must see one.
 from __future__ import annotations
 
 from .. import compat
+from .runtime import require_devices
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,5 +20,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_dd_mesh(n_ranks: int):
-    """1-D mesh for the MD virtual-DD inference layer (axis "dd")."""
-    return compat.make_mesh((n_ranks,), ("dd",))
+    """1-D mesh for the MD virtual-DD inference layer (axis "dd") over the
+    first ``n_ranks`` devices JAX sees."""
+    devices = require_devices(n_ranks, f"make_dd_mesh({n_ranks})")
+    return compat.make_mesh((n_ranks,), ("dd",), devices=devices)

@@ -158,6 +158,8 @@ def main():
     ap.add_argument("--batch-window-ms", type=float, default=2.0)
     ap.add_argument("--timeout-s", type=float, default=60.0)
     args = ap.parse_args()
+    from .runtime import enable_compile_cache
+    enable_compile_cache()
 
     backend = args.backend
     if backend == "auto":

@@ -32,6 +32,8 @@ def main():
     ap.add_argument("--d-model", type=int, default=256)
     ap.add_argument("--n-layers", type=int, default=4)
     args = ap.parse_args()
+    from .runtime import enable_compile_cache
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp

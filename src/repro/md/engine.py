@@ -54,7 +54,8 @@ from ..obs import Tracer
 from . import observables
 from .forcefield import ForceFieldConfig, classical_energy
 from .integrators import MDState, init_velocities, leapfrog_step, berendsen_rescale
-from .neighbors import NeighborList, build_neighbor_list, needs_rebuild
+from .neighbors import (NeighborList, build_neighbor_list,
+                        cell_capacity_scale, needs_rebuild)
 from .system import System
 
 
@@ -134,6 +135,7 @@ class MDEngine:
         # jitted windows: force the per-step host loop for them
         self._host_special = bool(getattr(special_force, "host_side", False))
         self._cell_cap_scale = 1.0
+        self._cells_sized = False        # sized from the first list build
         self._build_fns()
         self._window_cache: dict[int, Callable] = {}
         self.timings: dict[str, float] = self._init_timings()
@@ -365,7 +367,15 @@ class MDEngine:
         self._window_cache.clear()   # windows close over the old capacity
 
     def _build_nlist_grown(self, positions) -> NeighborList:
-        """Build the classical list, doubling capacity until it fits."""
+        """Build the classical list, doubling capacity until it fits.  The
+        first build sizes the cells from the busiest one in ``positions``."""
+        if not self._cells_sized:
+            cfg = self.config
+            self._cell_cap_scale = max(self._cell_cap_scale,
+                                       cell_capacity_scale(
+                                           positions, self.system.box,
+                                           cfg.cutoff, cfg.skin))
+            self._cells_sized = True
         while True:
             nlist = self.build_nlist(positions)
             if not bool(jnp.any(nlist.overflow)):

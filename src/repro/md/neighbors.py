@@ -111,6 +111,30 @@ def cell_list_neighbor_list(pos: jax.Array, box: jax.Array, cutoff: float,
                         ref_positions=pos, overflow=overflow)
 
 
+def _density_cell_capacity(n: int, box: np.ndarray, r: float) -> float:
+    """Capacity of one cell of edge ``r`` from the box's mean density."""
+    return max(8, 2.5 * n / float(np.prod(box)) * r ** 3 + 8)
+
+
+def cell_capacity_scale(pos, box, cutoff: float, skin: float = 0.0,
+                        slack: float = 1.5) -> float:
+    """The ``cell_cap_scale`` at which the busiest cell of ``pos`` (host
+    values, leading batch dims allowed) fits with ``slack``.  The density
+    estimate sees only the box's mean, which a solvated protein's chain far
+    exceeds; sizing from the first frame saves doublings at start."""
+    box = np.asarray(box, np.float64)
+    r = cutoff + skin
+    grid = np.array(cells.grid_dims(box, r))
+    pos = np.asarray(pos, np.float64)
+    n = pos.shape[-2]
+    frac = np.clip(np.floor(pos.reshape(-1, 3) / (box / grid)).astype(int),
+                   0, grid - 1)
+    frame = np.arange(frac.shape[0]) // n   # one bin range per batch entry
+    ids = frame * int(np.prod(grid)) + np.ravel_multi_index(frac.T, grid)
+    busiest = np.bincount(ids).max()
+    return max(1.0, slack * busiest / _density_cell_capacity(n, box, r))
+
+
 def build_neighbor_list(pos: jax.Array, box, cutoff: float, capacity: int,
                         half: bool = False, skin: float = 0.0,
                         cell_cap_scale: float = 1.0) -> NeighborList:
@@ -124,9 +148,8 @@ def build_neighbor_list(pos: jax.Array, box, cutoff: float, capacity: int,
     r = cutoff + skin
     grid = _cell_grid(np.asarray(box), r)
     if min(grid) >= 3:
-        n = pos.shape[0]
-        density = n / float(np.prod(np.asarray(box)))
-        cell_cap = int(cell_cap_scale * max(8, 2.5 * density * r ** 3 + 8))
+        cell_cap = int(cell_cap_scale * _density_cell_capacity(
+            pos.shape[0], np.asarray(box), r))
         return cell_list_neighbor_list(pos, box, r, capacity, grid, cell_cap, half)
     return brute_force_neighbor_list(pos, box, r, capacity, half)
 

@@ -238,11 +238,15 @@ def build_solvated_protein(n_residues: int, water_per_protein_atom: float = 3.0,
     rng = np.random.default_rng(seed + 1)
     grid = np.stack(np.meshgrid(*[np.arange(n_side)] * 3, indexing="ij"), -1)
     wpos = (grid.reshape(-1, 3) + 0.5) * (box / n_side)
-    # carve out waters overlapping the protein
+    # carve out waters within 0.3 nm of any protein atom (about one LJ
+    # sigma: a water left closer sits deep in the r^-12 wall), in chunks so
+    # the (waters x protein atoms) distance block stays small
     center = box / 2
     ppos = prot["positions"] - prot["positions"].mean(0) + center
-    d2 = ((wpos[:, None, :] - ppos[None, ::4, :]) ** 2).sum(-1).min(1)
-    keep = d2 > 0.25 ** 2
+    keep = np.ones(len(wpos), bool)
+    for i in range(0, len(wpos), 1024):
+        d2 = ((wpos[i:i + 1024, None, :] - ppos[None, :, :]) ** 2).sum(-1)
+        keep[i:i + 1024] = d2.min(1) > 0.3 ** 2
     wpos = wpos[keep]
     n_wat = len(wpos)
 

@@ -183,3 +183,25 @@ def test_subdomain_nbr_list_cells_overflow_flags():
     _, _, ovf = _subdomain_nbr_list_cells(buf, mask, 0.5, 64, origin, (1, 1, 1),
                                           cell_capacity=n)
     assert bool(ovf)
+
+
+def test_subcell_capacity_fits_one_chip_at_liquid_density():
+    """One rank's candidate planes for 4,096 atoms at 100 atoms/nm^3 and the
+    paper's r_c = 0.8 nm stay under a quarter of a v5e's 16 GB of HBM.
+
+    The planes are what ``_subdomain_nbr_list_cells`` hands the cell
+    filter: candidate index, dx, dy, dz and validity, each (C, 27 * cap)
+    with the candidate axis padded to 128 lanes.  Sizing ``subcell_capacity``
+    from a 2x2x2 block bound instead of the grid the runtime bins gave
+    cap = 704 and ~14.6 GB here."""
+    n, density, rcut = 4096, 100.0, 0.8
+    edge = (n / density) ** (1 / 3)
+    coords = np.random.default_rng(0).uniform(0.0, edge, (n, 3))
+    cfg = suggest_config(n, np.full(3, edge), 1, rcut, nbr_capacity=64,
+                         coords=coords)
+    mean_occupancy = density * rcut ** 3
+    assert cfg.subcell_capacity <= 2.5 * mean_occupancy
+    c = cfg.local_capacity + cfg.ghost_capacity
+    lanes = -(-27 * cfg.subcell_capacity // 128) * 128
+    footprint = 5 * c * lanes * 4
+    assert footprint < 4e9, footprint

@@ -67,7 +67,9 @@ def test_displacement_rebuilds_inside_scan(small_system):
     system, pos, nn_idx, model, params = small_system
     runs = {}
     for mode in ["scan", "step"]:
-        cfg = EngineConfig(loop_mode=mode, rebuild_every=1000, skin=0.02,
+        # the fastest atoms move ~5e-3 nm in these 10 steps at 200 K: a
+        # 2e-3 nm skin makes the displacement criterion fire several times
+        cfg = EngineConfig(loop_mode=mode, rebuild_every=1000, skin=0.002,
                            **_CFG)
         eng = MDEngine(system, cfg, special_force=_provider(small_system))
         runs[mode] = (eng.run(eng.init_state(pos, 200.0), 10), eng)
@@ -99,6 +101,18 @@ def test_capacity_overflow_grows_instead_of_raising(small_system):
     assert bool(jnp.isfinite(st.positions).all())
     assert eng.diagnostics["capacity_growths"], eng.diagnostics
     assert eng.config.neighbor_capacity > 2
+
+
+def test_clustered_system_starts_without_growth():
+    """The protein chain packs its cells far above the box's mean density;
+    the engine sizes the cells from the first frame instead of doubling."""
+    system, pos, _ = build_solvated_protein(96)
+    eng = MDEngine(system, EngineConfig(cutoff=0.9, neighbor_capacity=96,
+                                        dt=0.0005, thermostat_t=200.0))
+    st = eng.run(eng.init_state(pos, 200.0), 2)
+    assert bool(jnp.isfinite(st.positions).all())
+    assert eng.diagnostics["capacity_growths"] == []
+    assert eng.config.neighbor_capacity == 96
 
 
 def test_observe_and_checkpoint_cadence(small_system, tmp_path):

@@ -4,7 +4,7 @@ import numpy as np
 from hypothesis import given, settings, strategies as st
 
 from repro.md.neighbors import (brute_force_neighbor_list,
-                                build_neighbor_list,
+                                build_neighbor_list, cell_capacity_scale,
                                 cell_list_neighbor_list, minimum_image,
                                 needs_rebuild)
 
@@ -66,3 +66,24 @@ def test_needs_rebuild_on_displacement():
     assert not bool(needs_rebuild(nl, pos, box, 0.2))
     moved = pos.at[0].add(jnp.asarray([0.15, 0.0, 0.0]))
     assert bool(needs_rebuild(nl, moved, box, 0.2))
+
+
+def test_cell_capacity_scale_fits_a_clustered_frame():
+    # 200 atoms spread over the box and 100 packed into one cell: the
+    # density-derived cell capacity overflows, the sized one does not, and
+    # in a batch of frames the busiest frame decides
+    rng = np.random.default_rng(5)
+    box = np.full(3, 4.0, np.float32)
+    spread = rng.uniform(0, 4, (300, 3)).astype(np.float32)
+    pos = np.concatenate([spread[:200],
+                          2.0 + rng.uniform(-0.3, 0.3, (100, 3))])
+    pos = pos.astype(np.float32)
+    scale = cell_capacity_scale(pos, box, 1.2, skin=0.1)
+    assert scale > 1
+    assert cell_capacity_scale(spread, box, 1.2, skin=0.1) == 1.0
+    assert cell_capacity_scale(np.stack([spread, pos]), box, 1.2,
+                               skin=0.1) == scale
+    default = build_neighbor_list(pos, box, 1.2, 256, half=True, skin=0.1)
+    sized = build_neighbor_list(pos, box, 1.2, 256, half=True, skin=0.1,
+                                cell_cap_scale=scale)
+    assert bool(default.overflow) and not bool(sized.overflow)

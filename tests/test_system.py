@@ -60,7 +60,7 @@ def test_dp_md_slower_than_classical_md():
 
     eng_cl = MDEngine(system, cfgE)
     st = eng_cl.init_state(pos, 100.0)
-    eng_cl.run(st, 3)  # warmup/compile
+    eng_cl.run(st, 10)  # warmup: compiles the 10-step window timed below
     t0 = time.perf_counter()
     eng_cl.run(st, 10)
     t_classical = time.perf_counter() - t0
@@ -72,7 +72,7 @@ def test_dp_md_slower_than_classical_md():
                                    nbr_capacity=48)
     eng_dp = MDEngine(system, cfgE, special_force=provider)
     st2 = eng_dp.init_state(pos, 100.0)
-    eng_dp.run(st2, 3)
+    eng_dp.run(st2, 10)
     t0 = time.perf_counter()
     eng_dp.run(st2, 10)
     t_dp = time.perf_counter() - t0
