@@ -14,7 +14,7 @@ def main() -> None:
     from . import (comms_overlap, dd_reuse, dd_scaling, dp_inference,
                    ensemble_throughput, fig7_training, fig8_validation,
                    fig9_overhead, fig10_strong_scaling, fig11_weak_scaling,
-                   fig12_breakdown, roofline_bench, serve_throughput)
+                   roofline_bench, serve_throughput)
     modules = [
         ("dd_scaling", dd_scaling),
         ("dd_reuse", dd_reuse),
@@ -25,7 +25,6 @@ def main() -> None:
         ("fig10_strong_scaling", fig10_strong_scaling),
         ("fig11_weak_scaling", fig11_weak_scaling),
         ("fig9_overhead", fig9_overhead),
-        ("fig12_breakdown", fig12_breakdown),
         ("fig8_validation", fig8_validation),
         ("fig7_training", fig7_training),
         ("roofline_bench", roofline_bench),

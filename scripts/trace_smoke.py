@@ -4,12 +4,12 @@
 Forces 8 host devices, runs a solvated-protein MD trajectory with the
 distributed Deep-Potential provider under ``ObsConfig(enabled=True)``
 (fused-scan windows, so per-step dd counters come out of ``lax.scan``),
-adds the calibrated Fig. 12 phase probes of the fused force driver, then:
+then:
 
 * writes + re-reads the JSONL event log (schema-validated both ways),
 * writes the Chrome-trace (Perfetto) view,
-* prints the ``trace_report`` rendering (phase table, stage fractions,
-  per-rank imbalance, step counters).
+* prints the ``trace_report`` rendering (phase table, per-rank
+  imbalance, step counters) and the run's ``md.run.*`` registry gauges.
 
 The committed ``experiments/traces/example_8rank_trace.jsonl`` is this
 script's output; CI runs it fresh on every push and uploads the artifact.
@@ -38,12 +38,12 @@ def main(argv=None) -> int:
     import jax
     import numpy as np
 
-    from repro.core import DeepmdForceProvider, ForcePipeline, suggest_config
+    from repro.core import DeepmdForceProvider, suggest_config
     from repro.dp import DPModel, paper_dpa1_config
     from repro.launch.mesh import make_dd_mesh
     from repro.md import (EngineConfig, MDEngine, build_solvated_protein,
                           mark_nn_group)
-    from repro.obs import ObsConfig, Tracer, report, timed_prefix_phases
+    from repro.obs import ObsConfig, Tracer, report
 
     assert len(jax.devices()) >= N_RANKS, (
         f"need {N_RANKS} devices, got {len(jax.devices())} — XLA_FLAGS was "
@@ -67,19 +67,10 @@ def main(argv=None) -> int:
                                         dt=0.0005, thermostat_t=200.0),
                    special_force=prov, obs=tracer)
     print(f"running {args.steps} instrumented steps on {N_RANKS} ranks ...")
-    state = eng.run(eng.init_state(pos, 200.0), args.steps)
-
-    # Fig. 12 phase attribution of the fused distributed driver via nested
-    # prefix probes (gather ⊂ assembly ⊂ inference ⊂ force_reduce)
-    nn_pos = jax.numpy.asarray(np.asarray(state.positions)[np.asarray(nn_idx)])
-    nn_types = jax.numpy.asarray(np.asarray(system.types)[np.asarray(nn_idx)])
-    probes = ForcePipeline(model, dd, mesh, np.asarray(system.box),
-                           len(nn_idx)).build_phase_probes()
-    thunks = {k: (lambda fn=fn: fn(params, nn_pos, nn_types))
-              for k, fn in probes.items()}
-    phases = timed_prefix_phases(tracer, thunks, iters=3, warmup=1)
-    print("fused-driver phases:",
-          {k: f"{v * 1e3:.2f}ms" for k, v in phases.items()})
+    eng.run(eng.init_state(pos, 200.0), args.steps)
+    gauges = tracer.registry.snapshot()["gauges"]
+    print("run totals:", {k: g["value"] for k, g in sorted(gauges.items())
+                          if k.startswith("md.run.")})
 
     os.makedirs(args.outdir, exist_ok=True)
     jsonl = os.path.join(args.outdir, args.name + ".jsonl")

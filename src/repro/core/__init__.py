@@ -11,8 +11,7 @@ from .ddinfer import (DDConfig, DDState, suggest_config,  # noqa: F401
                       single_domain_forces, single_domain_state,
                       single_domain_forces_nlist,
                       single_domain_forces_batched,
-                      masked_neighbor_list, make_padded_batch_fn,
-                      make_phase_probe_fns)
+                      masked_neighbor_list, make_padded_batch_fn)
 from .pipeline import ForcePipeline, Stage  # noqa: F401
 from .nnpot import DeepmdForceProvider, UnitConversion  # noqa: F401
 from ..backend import (ForceBackend, ForceRequest, ForceResult,  # noqa: F401

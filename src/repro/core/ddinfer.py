@@ -645,18 +645,6 @@ def make_distributed_force_fn(model: DPModel, cfg: DDConfig, mesh: Mesh,
     return _pipeline(model, cfg, mesh, box, n_atoms).build_force_fn()
 
 
-def make_phase_probe_fns(model: DPModel, cfg: DDConfig, mesh: Mesh, box,
-                         n_atoms: int) -> dict:
-    """Deprecation shim: ``ForcePipeline(...).build_phase_probes()``.
-
-    Ordered ``{phase: jitted f(params, coords, types)}`` prefix probes
-    attributing the fused driver's cost to its stages (paper Fig. 12);
-    the last entry IS the full fused driver.
-    """
-    _warn_shim("make_phase_probe_fns", "build_phase_probes")
-    return _pipeline(model, cfg, mesh, box, n_atoms).build_phase_probes()
-
-
 # ---------------------------------------------------------------------------
 # Replica-batched drivers: R independent replicas of the same system as one
 # SPMD program on a 2-D (replica x dd) mesh.  Batching is a pipeline

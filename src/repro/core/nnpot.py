@@ -153,8 +153,8 @@ class DeepmdForceProvider:
         """Hook: (re)build the jitted distributed drivers from ONE
         :class:`~repro.core.pipeline.ForcePipeline` — called at init and
         after every ``grow`` (capacities may have changed).  The pipeline is
-        exposed as ``self.pipeline`` so callers (serve executors, phase
-        probes) can derive further compositions from the same stage list."""
+        exposed as ``self.pipeline`` so callers (serve executors) can derive
+        further compositions from the same stage list."""
         if self.dd_config is not None:
             self.pipeline = ForcePipeline(self.model, self.dd_config,
                                           self.mesh, self.box_model,

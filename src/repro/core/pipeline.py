@@ -19,9 +19,8 @@ each a per-rank body that runs inside ONE ``shard_map`` region:
   plus the diagnostics dictionary.
 
 Every public driver is a thin composition over these bodies:
-``build_force_fn`` (fused per-step), ``build_assembly_fn`` +
-``build_evaluation_fn`` + ``build_check_fn`` (amortized split), and
-``build_phase_probes`` (a generic prefix-walk over the stage list).
+``build_force_fn`` (fused per-step), and ``build_assembly_fn`` +
+``build_evaluation_fn`` + ``build_check_fn`` (amortized split).
 Replica batching is a *transform*, not a second copy of each driver: the
 :class:`_AxisOps` adapter moves every collective to the batched atom axis
 and vmaps the per-replica stage bodies on the (replica x dd) mesh.
@@ -492,16 +491,13 @@ def _evaluate_rank_overlap(model: DPModel, params, coords_all, ref_all,
 @dataclasses.dataclass(frozen=True)
 class Stage:
     """One pipeline stage: a per-rank body over a context dict, with its
-    in/out keys declared and an optional probe reducer (a per-rank scalar
-    that depends on every expensive output, so a prefix program through
-    this stage measures exactly the work up to and including it)."""
+    in/out keys declared and the name scope its operations carry."""
 
     name: str
     scope: str
     inputs: tuple
     outputs: tuple
     body: Callable            # body(ctx) -> None (mutates ctx)
-    probe: Optional[Callable] = None   # probe(ctx) -> per-rank scalar
 
 
 class ForcePipeline:
@@ -556,8 +552,8 @@ class ForcePipeline:
     # -- stage bodies (per-rank; ctx maps names -> arrays) -------------------
 
     def _fused_stages(self) -> tuple:
-        """The fused per-step stage list — also the probe prefix-walk order.
-        Probe names keep the Fig. 12 phase vocabulary."""
+        """The fused per-step stage list; stage names keep the Fig. 12 phase
+        vocabulary."""
         model, cfg, box, ax = self.model, self.cfg, self.box, self.ax
         rcut, n_atoms = self.rcut, self.n_atoms
 
@@ -606,19 +602,12 @@ class ForcePipeline:
 
         return (
             Stage("gather", "obs.gather", ("coords_shard",), ("coords_all",),
-                  gather, probe=lambda ctx: ctx["coords_all"].sum()),
+                  gather),
             Stage("assembly", "obs.assembly", ("coords_all", "types_all"),
-                  ("st",), assemble,
-                  # depend on every expensive assembly output so nothing is
-                  # DCE'd (the routing table is a collective — skip it)
-                  probe=lambda ctx: (
-                      ctx["st"]["nbr_idx"].sum() + ctx["st"]["nbr_mask"].sum()
-                      + ctx["st"]["local_count"].astype(jnp.float32)
-                      + ctx["st"]["ghost_count"].astype(jnp.float32))),
+                  ("st",), assemble),
             Stage("inference", "obs.inference",
                   ("params", "coords_all", "st"),
-                  ("e_local", "f_global", "trim_ovf", "stats"), evaluate,
-                  probe=lambda ctx: ctx["e_local"] + ctx["f_global"].sum()),
+                  ("e_local", "f_global", "trim_ovf", "stats"), evaluate),
             Stage("force_reduce", "obs.force_reduce",
                   ("e_local", "f_global", "st"),
                   ("energy", "forces", "diag"), reduce),
@@ -906,43 +895,3 @@ class ForcePipeline:
             return (disp2 > (0.5 * cfg.skin) ** 2) | (state.overflow > 0)
 
         return jax.jit(check)
-
-    def build_phase_probes(self) -> dict:
-        """Prefix probes attributing the fused driver's cost to its stages —
-        a generic walk over ``self.stages``: probe *k* executes the pipeline
-        through stage *k* and reduces to a per-rank scalar with no further
-        collective, so successive wall-time differences
-        (``repro.obs.timed_prefix_phases``) measure the paper's Fig. 12
-        shares.  The last entry IS the full fused driver."""
-        self._require_model("build_phase_probes")
-        if self.ax.batched:
-            raise ValueError("build_phase_probes supports the unbatched "
-                             "layout only (the probe reducers emit one "
-                             "scalar per rank)")
-        ax = self.ax
-        probes = {}
-        for i, stage in enumerate(self.stages):
-            if stage.probe is None:
-                continue
-            prefix = self.stages[: i + 1]
-
-            def per_rank(params, coords_shard, types_all, _prefix=prefix,
-                         _stage=stage):
-                ctx = {"params": params, "coords_shard": coords_shard,
-                       "types_all": types_all}
-                for s in _prefix:
-                    s.body(ctx)
-                return jnp.reshape(_stage.probe(ctx), (1,))
-
-            mapped = compat.shard_map(per_rank, mesh=self.mesh,
-                                      in_specs=(P(), ax.spec(None), P()),
-                                      out_specs=ax.spec())
-
-            def fn(params, coords, types, _mapped=mapped):
-                coords_p, types_p = self._pad(coords, types)
-                return _mapped(params, coords_p, types_p)
-
-            probes[stage.name] = jax.jit(fn)
-
-        probes[self.stages[-1].name] = self.build_force_fn()
-        return probes

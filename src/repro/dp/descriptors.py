@@ -127,31 +127,38 @@ def apply_descriptor(params: dict, cfg: DescriptorConfig, stats: EnvStats,
     """
     cfg.validate()
     cd = precision.compute_dtype(dtype)
-    if cfg.use_pallas:
-        R, r_hat, dist, sw = _env_planes_pallas(coords_center, coords_nbr,
-                                                nbr_mask, cfg)
-    else:
-        R, r_hat, dist, sw = env_matrix_shifted(coords_center, coords_nbr,
-                                                nbr_mask, cfg.rcut_smth,
-                                                cfg.rcut)
-    R = stats.normalize(R, types_center) * nbr_mask[..., None]
+    # dp.* name scopes: HLO metadata naming the descriptor's stages
+    with jax.named_scope("dp.env_mat"):
+        if cfg.use_pallas:
+            R, r_hat, dist, sw = _env_planes_pallas(coords_center,
+                                                    coords_nbr, nbr_mask, cfg)
+        else:
+            R, r_hat, dist, sw = env_matrix_shifted(coords_center,
+                                                    coords_nbr, nbr_mask,
+                                                    cfg.rcut_smth, cfg.rcut)
+        R = stats.normalize(R, types_center) * nbr_mask[..., None]
 
-    t_emb = params["type_embed"][jnp.clip(types_nbr, 0)]
-    feat = jnp.concatenate([sw[..., None], t_emb * nbr_mask[..., None]], -1)
-    g = mlp_apply(params["embed"], feat, compute_dtype=cd)   # (N, K, M1)
-    g = g * nbr_mask[..., None]
+    with jax.named_scope("dp.embedding"):
+        t_emb = params["type_embed"][jnp.clip(types_nbr, 0)]
+        feat = jnp.concatenate([sw[..., None], t_emb * nbr_mask[..., None]],
+                               -1)
+        g = mlp_apply(params["embed"], feat, compute_dtype=cd)  # (N, K, M1)
+        g = g * nbr_mask[..., None]
 
     if cfg.kind == "dpa1" and cfg.attn_layers > 0:
-        sw_env = sw * dist  # recover the [0,1] polynomial envelope from s(r)
-        g = nbr_attention_stack_op(
-            g, r_hat[..., 0], r_hat[..., 1], r_hat[..., 2], sw_env, nbr_mask,
-            *_stack_params(params["attn"]), heads=cfg.attn_heads,
-            compute_dtype=dtype, use_pallas=cfg.use_pallas)
+        with jax.named_scope("dp.attention"):
+            sw_env = sw * dist  # the [0,1] polynomial envelope from s(r)
+            g = nbr_attention_stack_op(
+                g, r_hat[..., 0], r_hat[..., 1], r_hat[..., 2], sw_env,
+                nbr_mask, *_stack_params(params["attn"]),
+                heads=cfg.attn_heads, compute_dtype=dtype,
+                use_pallas=cfg.use_pallas)
 
     # bilinear G^T R R^T G reduction: always fp32 (force-critical)
-    k_norm = 1.0 / cfg.sel
-    g = g.astype(jnp.float32)
-    R = R.astype(jnp.float32)
-    gr = jnp.einsum("nkm,nka->nma", g, R) * k_norm     # (N, M1, 4)
-    d = jnp.einsum("nma,npa->nmp", gr, gr[:, : cfg.axis_neuron, :])
-    return d.reshape(d.shape[0], -1)                   # (N, M1*M2)
+    with jax.named_scope("dp.descriptor_reduce"):
+        k_norm = 1.0 / cfg.sel
+        g = g.astype(jnp.float32)
+        R = R.astype(jnp.float32)
+        gr = jnp.einsum("nkm,nka->nma", g, R) * k_norm     # (N, M1, 4)
+        d = jnp.einsum("nma,npa->nmp", gr, gr[:, : cfg.axis_neuron, :])
+        return d.reshape(d.shape[0], -1)                   # (N, M1*M2)

@@ -76,22 +76,25 @@ class DPModel:
                                 self.stats, coords_center, coords_nbr,
                                 types_center, types_nbr, nbr_mask,
                                 dtype=self.cfg.dtype)
-        e = mlp_apply(params["fitting"], desc,
-                      compute_dtype=precision.compute_dtype(self.cfg.dtype)
-                      )[..., 0]
-        e = e + params["bias"][jnp.clip(types_center, 0)]
-        return e * atom_mask
+        with jax.named_scope("dp.fitting"):
+            e = mlp_apply(params["fitting"], desc,
+                          compute_dtype=precision.compute_dtype(
+                              self.cfg.dtype))[..., 0]
+            e = e + params["bias"][jnp.clip(types_center, 0)]
+            return e * atom_mask
 
     def _atomic_e(self, params, coords, types, nbr_idx, nbr_mask, box=None):
         """(C,) per-atom energies over a buffer; padded-neighbor safe."""
-        safe = jnp.where(nbr_idx >= 0, nbr_idx, 0)
-        coords_nbr = coords[safe]
-        if box is not None:
-            dr = coords_nbr - coords[:, None, :]
-            dr = dr - box * jnp.round(dr / box)
-            coords_nbr = coords[:, None, :] + dr
+        with jax.named_scope("dp.nbr_gather"):
+            safe = jnp.where(nbr_idx >= 0, nbr_idx, 0)
+            coords_nbr = coords[safe]
+            if box is not None:
+                dr = coords_nbr - coords[:, None, :]
+                dr = dr - box * jnp.round(dr / box)
+                coords_nbr = coords[:, None, :] + dr
+            types_nbr = types[safe]
         return self.atomic_energies(params, coords, coords_nbr, types,
-                                    types[safe], nbr_mask,
+                                    types_nbr, nbr_mask,
                                     jnp.ones(coords.shape[0], coords.dtype))
 
     def total_energy(self, params, coords, types, nbr_idx, nbr_mask,

@@ -174,7 +174,7 @@ class EnsembleEngine(MDEngine):
     # -- batched-engine hooks ----------------------------------------------
 
     def _abs_step(self, state) -> int:
-        return int(state.step[0])
+        return self._host_read(int, state.step[0])
 
     def _post_segment(self, state, e_cl, e_sp, i: int):
         ex = self.ens.exchange_interval

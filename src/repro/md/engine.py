@@ -58,6 +58,9 @@ from .neighbors import (NeighborList, build_neighbor_list,
                         cell_capacity_scale, needs_rebuild)
 from .system import System
 
+# the run loop's host spans, whose seconds per run are published as gauges
+RUN_SPANS = ("md.window", "md.verdict", "md.rebuild")
+
 
 class ForceProvider(Protocol):
     """NNPot-style special-force provider (paper Sec. IV-A).
@@ -233,10 +236,17 @@ class MDEngine:
         system = self.system
         special = self.special_force
 
-        rb = self._check_rebuild(nlist, state.positions)
-        nlist = jax.lax.cond(jnp.any(rb), lambda p, nl: self.build_nlist(p),
-                             lambda p, nl: nl, state.positions, nlist)
-        e_cl, f = self._classical_fn(state.positions, nlist)
+        # name scopes are HLO metadata only: they let a device trace credit
+        # the window's operations to the engine's own stages
+        with jax.named_scope("md.rebuild_check"):
+            rb = self._check_rebuild(nlist, state.positions)
+        with jax.named_scope("md.neighbor"):
+            nlist = jax.lax.cond(jnp.any(rb),
+                                 lambda p, nl: self.build_nlist(p),
+                                 lambda p, nl: nl, state.positions, nlist)
+        with jax.named_scope("md.classical"):
+            e_cl, f = self._classical_fn(state.positions, nlist)
+        f_sp = None
         e_sp = jnp.zeros(self._batch_shape, f.dtype)
         sp_rb = jnp.zeros(self._batch_shape, bool)
         sp_ovf = jnp.zeros(self._batch_shape, bool)
@@ -265,12 +275,14 @@ class MDEngine:
             else:
                 e_sp, f_sp = self._eval_special_stateless(state.positions,
                                                           system.box)
-            f = f + f_sp
-        if self.faults is not None:
-            # exact-step injection seam; a fully fired plan contributes
-            # nothing and traces the unfaulted program
-            f, sp_ovf = self.faults.apply_engine(state.step, f, sp_ovf)
-        new = self._integrate_fn(state, f)
+        with jax.named_scope("md.integrate"):
+            if f_sp is not None:
+                f = f + f_sp
+            if self.faults is not None:
+                # exact-step injection seam; a fully fired plan contributes
+                # nothing and traces the unfaulted program
+                f, sp_ovf = self.faults.apply_engine(state.step, f, sp_ovf)
+            new = self._integrate_fn(state, f)
         trip = None
         if self._guard_on:
             trip = step_guard_trip(self.guard, state.positions, new,
@@ -336,6 +348,15 @@ class MDEngine:
         self._window_cache[k] = fn
         return fn
 
+    def lower_window(self, state: MDState, nlist: NeighborList,
+                     sp_state) -> jax.stages.Lowered:
+        """The ``rebuild_every``-step window program lowered at these
+        arguments.  Its compiled text (``.compile().as_text()``) carries
+        the ``md.*``, ``obs.*`` and ``dp.*`` name scopes of every operation,
+        which is how a device trace of the window is credited to stages."""
+        return self._window_fn(self.config.rebuild_every).lower(
+            state, nlist, sp_state if self._stateful else None)
+
     # -- lifecycle ---------------------------------------------------------
 
     def init_state(self, positions: jax.Array, temperature: float = 300.0,
@@ -378,7 +399,7 @@ class MDEngine:
             self._cells_sized = True
         while True:
             nlist = self.build_nlist(positions)
-            if not bool(jnp.any(nlist.overflow)):
+            if not self._host_read(bool, jnp.any(nlist.overflow)):
                 return nlist
             self._grow_neighbor_capacity()
 
@@ -388,7 +409,8 @@ class MDEngine:
         special = self.special_force
         for _ in range(self.config.max_capacity_growths + 1):
             sp_state = special.assemble(positions)
-            if not bool(jnp.any(special.state_overflow(sp_state))):
+            if not self._host_read(bool,
+                                   jnp.any(special.state_overflow(sp_state))):
                 return sp_state
             special.grow()
             self.diagnostics["special_growths"] += 1
@@ -426,15 +448,16 @@ class MDEngine:
         Capacity overflow takes precedence over a guard trip: an overflowed
         window computed truncated forces, so any trip it reports is judged
         afresh on the grown replay."""
-        nlist_ovf = bool(jnp.any(flags["nlist_overflow"]))
-        sp_ovf = bool(jnp.any(flags["sp_overflow"]))
+        nlist_ovf = self._host_read(bool, jnp.any(flags["nlist_overflow"]))
+        sp_ovf = self._host_read(bool, jnp.any(flags["sp_overflow"]))
         if nlist_ovf or sp_ovf:
             return WindowVerdict("capacity_overflow",
                                  detail={"nlist": nlist_ovf,
                                          "special": sp_ovf})
         trip = flags.get("guard_trip")
-        if trip is not None and bool(jnp.any(trip)):
-            return WindowVerdict("guard_trip", trip_mask=np.asarray(trip))
+        if trip is not None and self._host_read(bool, jnp.any(trip)):
+            return WindowVerdict("guard_trip",
+                                 trip_mask=self._host_read(np.asarray, trip))
         return WindowVerdict("ok")
 
     def _run_segment_scan(self, state, nlist, sp_state, k: int):
@@ -452,20 +475,23 @@ class MDEngine:
         try:
             while True:
                 t0 = time.perf_counter()
-                with tracer.span("scan_window", phase="scan", steps=k):
+                with tracer.span("md.window", phase="scan", steps=k):
                     (state, nlist, sp_state, flags, e_cl,
                      e_sp), recs = self._window_fn(k)(*start)
                     jax.block_until_ready(state.positions)
                 self.timings["scan"] += time.perf_counter() - t0
-                verdict = self._window_verdict(flags)
+                with tracer.span("md.verdict", phase="verdict"):
+                    verdict = self._window_verdict(flags)
+                    if verdict.policy == "commit":
+                        # batched engines count per-trajectory triggers
+                        # (replica-steps)
+                        self.diagnostics["displacement_rebuilds"] += (
+                            self._host_read(int, jnp.sum(flags["rebuilds"])))
+                        self.diagnostics["special_rebuilds"] += (
+                            self._host_read(int,
+                                            jnp.sum(flags["sp_rebuilds"])))
+                        tracer.record_window(step0, k, recs)
                 if verdict.policy == "commit":
-                    # batched engines count per-trajectory triggers
-                    # (replica-steps)
-                    self.diagnostics["displacement_rebuilds"] += int(
-                        jnp.sum(flags["rebuilds"]))
-                    self.diagnostics["special_rebuilds"] += int(
-                        jnp.sum(flags["sp_rebuilds"]))
-                    tracer.record_window(step0, k, recs)
                     out = (state, nlist, sp_state, e_cl, e_sp)
                     if committed is not None:
                         # per-replica masking: untripped trajectories keep
@@ -482,15 +508,18 @@ class MDEngine:
                     if not injected:
                         # grow whichever capacity overflowed — correctness
                         # over throughput on the rare growth event
-                        if verdict.detail["nlist"]:
-                            self._grow_neighbor_capacity()
-                            nlist0 = self._build_nlist_grown(state0.positions)
-                        if self._stateful and verdict.detail["special"]:
-                            self.special_force.grow()
-                            self.diagnostics["special_growths"] += 1
-                            self._window_cache.clear()
-                            sp_state0 = self._assemble_special_grown(
-                                state0.positions)
+                        with tracer.span("md.rebuild", phase="neighbor",
+                                         why="grow"):
+                            if verdict.detail["nlist"]:
+                                self._grow_neighbor_capacity()
+                                nlist0 = self._build_nlist_grown(
+                                    state0.positions)
+                            if self._stateful and verdict.detail["special"]:
+                                self.special_force.grow()
+                                self.diagnostics["special_growths"] += 1
+                                self._window_cache.clear()
+                                sp_state0 = self._assemble_special_grown(
+                                    state0.positions)
                     # injected flag: disarmed above, replay unchanged
                     start = (state0, nlist0, sp_state0)
                     continue
@@ -522,7 +551,7 @@ class MDEngine:
             while True:
                 state, nlist, sp_state, e_cl, e_sp, trip = (
                     self._attempt_segment_step(*start, k))
-                if trip is None or not bool(jnp.any(trip)):
+                if trip is None or not self._host_read(bool, jnp.any(trip)):
                     out = (state, nlist, sp_state, e_cl, e_sp)
                     if committed is not None:
                         out = self._merge_rollback(committed, out, mask0)
@@ -530,12 +559,12 @@ class MDEngine:
                             "guard.recoveries").inc()
                     return out
                 self.diagnostics["window_reruns"] += 1
+                trip = self._host_read(np.asarray, trip)
                 if committed is None:
                     committed = (state, nlist, sp_state, e_cl, e_sp)
-                    mask0 = np.asarray(trip)
-                start = self._guard_rollback(start, step0, k,
-                                             np.asarray(trip), rollbacks,
-                                             dt0)
+                    mask0 = trip
+                start = self._guard_rollback(start, step0, k, trip,
+                                             rollbacks, dt0)
                 rollbacks += 1
         finally:
             if self.config.dt != dt0:
@@ -560,7 +589,8 @@ class MDEngine:
             rec = {"rebuild": 0, "sp_rebuild": 0} if want else {}
             t0 = time.perf_counter()
             with tracer.span("neighbor", phase="neighbor"):
-                if bool(jnp.any(self._check_rebuild(nlist, state.positions))):
+                if self._host_read(bool, jnp.any(
+                        self._check_rebuild(nlist, state.positions))):
                     nlist = self._build_nlist_grown(state.positions)
                     self.diagnostics["displacement_rebuilds"] += 1
                     if want:
@@ -580,7 +610,8 @@ class MDEngine:
                     if self._stateful:
                         e_sp, f_sp, fl = special.evaluate(state.positions,
                                                           sp_state)
-                        if bool(jnp.any(fl["needs_rebuild"])):
+                        if self._host_read(bool,
+                                           jnp.any(fl["needs_rebuild"])):
                             sp_state = self._assemble_special_grown(
                                 state.positions)
                             self.diagnostics["special_rebuilds"] += 1
@@ -588,7 +619,7 @@ class MDEngine:
                                 rec["sp_rebuild"] = 1
                             e_sp, f_sp, fl = special.evaluate(state.positions,
                                                               sp_state)
-                        while bool(jnp.any(fl["overflow"])):
+                        while self._host_read(bool, jnp.any(fl["overflow"])):
                             # evaluation-side overflow (e.g. k_eval trim):
                             # grow and recompute — mirrors the scan replay
                             special.grow()
@@ -717,8 +748,8 @@ class MDEngine:
         return (state0, nlist0, sp_state0)
 
     def _state_healthy(self, state) -> bool:
-        return bool(np.isfinite(np.asarray(state.positions)).all()
-                    and np.isfinite(np.asarray(state.velocities)).all())
+        return all(bool(np.isfinite(self._host_read(np.asarray, x)).all())
+                   for x in (state.positions, state.velocities))
 
     def _state_from_tree(self, tree) -> MDState:
         return MDState(**{key: jnp.asarray(v) for key, v in tree.items()})
@@ -781,42 +812,6 @@ class MDEngine:
             reason = f"{reason} (emergency checkpoint: {path})"
         raise raise_cls(reason)
 
-    def _calibrate_phases(self, state, nlist, sp_state) -> None:
-        """In-scan phase attribution for scan-mode runs (Fig. 9 fractions).
-
-        The fused window reports one ``scan`` wall-clock bucket; this times
-        each already-jitted stage once, warm, and records the durations as
-        ``calibrated`` spans (phases ``scan.neighbor`` / ``scan.classical``
-        / ``scan.inference`` / ``scan.integrate``) so ``trace_report``'s
-        stage-fraction table can decompose the bucket.  Measured on the
-        real jitted stage functions at the run's own state — not modeled."""
-        tracer = self.tracer
-        if not (tracer.enabled and tracer.config.calibrate):
-            return
-        probes: dict[str, Callable] = {
-            "scan.neighbor": lambda: self._check_rebuild(
-                nlist, state.positions),
-            "scan.classical": lambda: self._classical_fn(
-                state.positions, nlist),
-        }
-        special = self.special_force
-        if special is not None:
-            if self._stateful:
-                probes["scan.inference"] = lambda: special.evaluate(
-                    state.positions, sp_state)
-            else:
-                probes["scan.inference"] = lambda: (
-                    self._eval_special_stateless(state.positions,
-                                                 self.system.box))
-        probes["scan.integrate"] = lambda: self._integrate_fn(state,
-                                                              state.forces)
-        for name, thunk in probes.items():
-            jax.block_until_ready(thunk())       # warm (compile) pass
-            t0 = time.perf_counter()
-            jax.block_until_ready(thunk())
-            tracer.add_span(name, time.perf_counter() - t0, phase=name,
-                            calibrated=True)
-
     def run(self, state: MDState, n_steps: int,
             observe: Optional[Callable[[MDState, dict], None]] = None,
             observe_every: int = 10) -> MDState:
@@ -831,30 +826,18 @@ class MDEngine:
                     loop_mode="scan" if scan_mode else "step",
                     n_steps=int(n_steps),
                     n_atoms=int(self.system.masses.shape[0]))
+        tracer.begin_run()
         tracer.start_capture()
-        t0 = time.perf_counter()
-        with tracer.span("build", phase="neighbor"):
-            nlist = self._build_nlist_grown(state.positions)
-            sp_state = None
-            if self._stateful:
-                sp_state = self._assemble_special_grown(state.positions)
-        self.timings["neighbor"] += time.perf_counter() - t0
-        if scan_mode:
-            self._calibrate_phases(state, nlist, sp_state)
+        nlist, sp_state = self._rebuild_lists(state.positions, "build")
 
-        i = 0
+        i = windows = 0
         while i < n_steps:
             if i > 0 and i % cfg.rebuild_every == 0:
                 # cadence rebuild on the host (the redundant step-0 rebuild
                 # right after the pre-loop build is skipped)
-                t0 = time.perf_counter()
-                with tracer.span("cadence_rebuild", phase="neighbor"):
-                    nlist = self._build_nlist_grown(state.positions)
-                    if self._stateful:
-                        sp_state = self._assemble_special_grown(
-                            state.positions)
+                nlist, sp_state = self._rebuild_lists(state.positions,
+                                                      "cadence")
                 self.diagnostics["cadence_rebuilds"] += 1
-                self.timings["neighbor"] += time.perf_counter() - t0
 
             k = self._segment_len(i, self._abs_step(state), n_steps,
                                   observe is not None, observe_every)
@@ -872,6 +855,7 @@ class MDEngine:
                 state, nlist, sp_state, e_cl, e_sp = self._run_segment_scan(
                     state, nlist, sp_state, k)
             i += k
+            windows += 1
             state = self._post_segment(state, e_cl, e_sp, i)
             self._last_state = state
 
@@ -890,21 +874,50 @@ class MDEngine:
                 # carried list whose reference positions predate it), so a
                 # restart/rollback from this checkpoint replays the
                 # committed continuation bitwise (see _rollback_start)
-                t0 = time.perf_counter()
-                with tracer.span("checkpoint_rebuild", phase="neighbor"):
-                    nlist = self._build_nlist_grown(state.positions)
-                    if self._stateful:
-                        sp_state = self._assemble_special_grown(
-                            state.positions)
-                self.timings["neighbor"] += time.perf_counter() - t0
+                nlist, sp_state = self._rebuild_lists(state.positions,
+                                                      "checkpoint")
         tracer.stop_capture()
+        self._publish_run(n_steps, windows)
         tracer.flush()  # no-op unless ObsConfig.trace_dir is set
         return state
+
+    def _rebuild_lists(self, positions, why: str):
+        """The host-driven classical list and special-force assembly, with
+        their overflow checks, as one ``md.rebuild`` span tagged ``why``."""
+        t0 = time.perf_counter()
+        with self.tracer.span("md.rebuild", phase="neighbor", why=why):
+            nlist = self._build_nlist_grown(positions)
+            sp_state = (self._assemble_special_grown(positions)
+                        if self._stateful else None)
+        self.timings["neighbor"] += time.perf_counter() - t0
+        return nlist, sp_state
+
+    def _host_read(self, convert, value):
+        """``convert(value)``: the one door for the run loop's blocking
+        reads of device values, each counted into the tracer
+        (``host_reads``) when tracing is on."""
+        self.tracer.count("host_reads")
+        return convert(value)
+
+    def _publish_run(self, n_steps: int, windows: int) -> None:
+        """This run's totals as registry gauges, overwritten by the next
+        run: seconds per run-loop span (``md.run.span_s.<span>``), blocking
+        host reads, windows and steps.  Nothing when tracing is off."""
+        tracer = self.tracer
+        if not tracer.enabled:
+            return
+        span_s, counts = tracer.run_totals()
+        gauge = tracer.registry.gauge
+        for name in RUN_SPANS:
+            gauge(f"md.run.span_s.{name}").set(span_s.get(name, 0.0))
+        gauge("md.run.host_reads").set(counts.get("host_reads", 0))
+        gauge("md.run.windows").set(windows)
+        gauge("md.run.steps").set(n_steps)
 
     # -- batched-engine hooks (overridden by repro.ensemble) ---------------
 
     def _abs_step(self, state) -> int:
-        return int(state.step)
+        return self._host_read(int, state.step)
 
     def _post_segment(self, state, e_cl, e_sp, i: int):
         """Host boundary between fused windows (replica exchange hook)."""
@@ -913,9 +926,9 @@ class MDEngine:
     def _observation(self, state, e_cl, e_sp) -> dict:
         return {
             "step": self._abs_step(state),
-            "e_classical": float(e_cl),
-            "e_special": float(e_sp),
-            "temperature": float(observables.temperature(
+            "e_classical": self._host_read(float, e_cl),
+            "e_special": self._host_read(float, e_sp),
+            "temperature": self._host_read(float, observables.temperature(
                 state.velocities, self.system.masses)),
         }
 

@@ -7,7 +7,9 @@ One instrumented spine every layer reports into:
   metrics are built on these.
 * :mod:`repro.obs.trace` — :class:`ObsConfig` + :class:`Tracer`: host-side
   wall-clock spans (doubling as ``jax.profiler.TraceAnnotation`` so phases
-  show up in real XLA profiles) and device-side per-step/per-rank counters
+  show up in real XLA profiles), per-run host totals (span seconds, the
+  engine's blocking host reads) that ``MDEngine.run`` publishes as
+  ``md.run.*`` registry gauges, and device-side per-step/per-rank counters
   threaded through the dd diag payloads and carried out of ``lax.scan``
   windows as stacked arrays.
 * :mod:`repro.obs.export` — JSONL event log + Chrome-trace (Perfetto) span
@@ -22,9 +24,9 @@ programs are bitwise-identical with and without the plumbing
 (``benchmarks/dd_reuse.py`` measures the <2% overhead bound).
 """
 from .registry import Counter, Gauge, Histogram, Registry, get_registry
-from .trace import ObsConfig, Tracer, timed_prefix_phases
+from .trace import ObsConfig, Tracer
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "get_registry",
-    "ObsConfig", "Tracer", "timed_prefix_phases",
+    "ObsConfig", "Tracer",
 ]

@@ -6,9 +6,6 @@ renders:
 * a **phase table** — total wall time and share per ``phase`` tag across
   measured spans (the paper's Fig. 12: assembly / inference /
   force-reduction shares; the >90%-inference claim is checked here);
-* **calibrated stage fractions** — per-stage probe timings recorded by
-  scan-mode runs (``calibrated: true`` spans), the Fig. 9 overhead
-  decomposition reportable from the fused path;
 * a **per-rank imbalance table** — mean/max local+ghost cost per rank over
   time from the ``rank_cost`` step counters, plus the mesh-wide
   ``cost_ratio`` (max/mean) the paper names as the principal bottleneck;
@@ -30,18 +27,12 @@ def load(path: str) -> list[dict]:
     return events
 
 
-def _spans(events, calibrated: bool):
-    for ev in events:
-        if ev.get("type") != "span" or "phase" not in ev:
-            continue
-        if bool(ev.get("calibrated", False)) == calibrated:
-            yield ev
-
-
 def phase_table(events: list[dict]) -> dict:
     """Measured wall time per phase tag: {phase: {time_s, count, share}}."""
     agg: dict[str, dict] = {}
-    for ev in _spans(events, calibrated=False):
+    for ev in events:
+        if ev.get("type") != "span" or "phase" not in ev:
+            continue
         a = agg.setdefault(ev["phase"], {"time_s": 0.0, "count": 0})
         a["time_s"] += ev["dur"]
         a["count"] += 1
@@ -49,16 +40,6 @@ def phase_table(events: list[dict]) -> dict:
     for a in agg.values():
         a["share"] = a["time_s"] / total if total else 0.0
     return agg
-
-
-def stage_fractions(events: list[dict]) -> dict:
-    """Calibrated per-stage probe timings: {phase: {time_s, fraction}}."""
-    agg: dict[str, float] = {}
-    for ev in _spans(events, calibrated=True):
-        agg[ev["phase"]] = agg.get(ev["phase"], 0.0) + ev["dur"]
-    total = sum(agg.values())
-    return {k: {"time_s": v, "fraction": v / total if total else 0.0}
-            for k, v in agg.items()}
 
 
 def _step_events(events):
@@ -132,7 +113,6 @@ def counter_summary(events: list[dict]) -> dict:
 
 def summarize(events: list[dict]) -> dict:
     return {"phases": phase_table(events),
-            "stage_fractions": stage_fractions(events),
             "imbalance": imbalance_table(events),
             "counters": counter_summary(events)}
 
@@ -158,15 +138,6 @@ def render(events: list[dict]) -> str:
     if phases:
         parts.append("phase breakdown (measured spans, Fig. 12):")
         parts.extend(_fmt_phase_rows(phases, "time_s", "share"))
-
-    frac = stage_fractions(events)
-    if frac:
-        parts.append("scan-stage fractions (calibrated probes, Fig. 9):")
-        lines = [f"  {'stage':<14}{'time_ms':>12}{'fraction':>10}"]
-        for name, a in sorted(frac.items(), key=lambda kv: -kv[1]["time_s"]):
-            lines.append(f"  {name:<14}{a['time_s'] * 1e3:>12.3f}"
-                         f"{a['fraction'] * 100:>9.1f}%")
-        parts.extend(lines)
 
     imb = imbalance_table(events)
     if imb.get("ranks"):

@@ -1,15 +1,21 @@
 """Step-level tracer that works inside jitted code.
 
-Two complementary mechanisms, both behind one :class:`ObsConfig`:
+Three complementary mechanisms, all behind one :class:`ObsConfig`:
 
 * **Host-side wall-clock spans** (:meth:`Tracer.span`) wrap whole
   dispatches — assembly, inference, force reduction, integration, scan
   windows, server batches.  Every span doubles as a
   ``jax.profiler.TraceAnnotation``, so the exact same phase names show up
   in real XLA profiles captured with :meth:`Tracer.start_capture`
-  (``jax.profiler.start_trace``), and the dd drivers additionally wrap
-  their traced phases in ``jax.named_scope`` — zero runtime cost, pure
-  HLO metadata.
+  (``jax.profiler.start_trace``).  Inside the compiled programs the dd
+  drivers (``obs.*``), the MD engine's step (``md.*``) and the DP model
+  (``dp.*``) wrap their stages in ``jax.named_scope`` — zero runtime cost,
+  pure HLO metadata.
+
+* **Per-run host totals**: seconds per span name and host counters
+  (:meth:`Tracer.count`, e.g. the engine's blocking ``host_reads``) since
+  :meth:`Tracer.begin_run`; the MD engine publishes them as registry
+  gauges at the end of each run.
 
 * **Device-side per-step counters**: jitted step bodies assemble a small
   dict of scalars / short vectors out of the dd diag payloads
@@ -46,7 +52,7 @@ class ObsConfig:
     enabled: bool = False       # master switch; False = hard zero-overhead
     counters: bool = True       # device-side per-step counter records
     spans: bool = True          # host wall-clock spans (+ TraceAnnotation)
-    calibrate: bool = True      # per-stage probe timings for scan-mode runs
+    calibrate: bool = True      # accepted and ignored (probe timing retired)
     trace_dir: Optional[str] = None      # auto-flush events.jsonl here
     xla_trace_dir: Optional[str] = None  # jax.profiler.start_trace target
     max_events: int = 200_000   # event-buffer bound (drop + count past it)
@@ -87,9 +93,13 @@ class _Span:
         t1 = time.perf_counter()
         self._anno.__exit__(exc_type, exc, tb)
         tr = self._tracer
+        dur = t1 - self._t0
         tr._add({"type": "span", "name": self._name,
-                 "ts": self._t0 - tr._epoch, "dur": t1 - self._t0,
+                 "ts": self._t0 - tr._epoch, "dur": dur,
                  "tid": tr._tid(), **self._attrs})
+        with tr._lock:
+            tr._run_span_s[self._name] = (
+                tr._run_span_s.get(self._name, 0.0) + dur)
         return False
 
 
@@ -124,6 +134,8 @@ class Tracer:
         self._tids: dict[int, int] = {}
         self._epoch = time.perf_counter()
         self._capturing = False
+        self._run_span_s: dict[str, float] = {}
+        self._run_counts: dict[str, int] = {}
 
     @staticmethod
     def ensure(obs) -> "Tracer":
@@ -168,14 +180,23 @@ class Tracer:
             return _NULL_SPAN
         return _Span(self, name, attrs)
 
-    def add_span(self, name: str, dur_s: float, **attrs) -> None:
-        """Record a span with an externally measured duration (derived
-        phase attributions, e.g. prefix-probe differences)."""
-        if self.enabled and self.config.spans:
-            self._add({"type": "span", "name": name,
-                       "ts": time.perf_counter() - self._epoch,
-                       "dur": float(max(dur_s, 0.0)), "tid": self._tid(),
-                       **attrs})
+    def count(self, name: str, n: int = 1) -> None:
+        """Add ``n`` to this run's host counter ``name`` (e.g. the engine's
+        ``host_reads``).  Disabled -> nothing counted."""
+        if self.enabled:
+            with self._lock:
+                self._run_counts[name] = self._run_counts.get(name, 0) + n
+
+    def begin_run(self) -> None:
+        """Zero the per-run span seconds and host counters."""
+        with self._lock:
+            self._run_span_s.clear()
+            self._run_counts.clear()
+
+    def run_totals(self) -> tuple[dict, dict]:
+        """(seconds per span name, host counters) since :meth:`begin_run`."""
+        with self._lock:
+            return dict(self._run_span_s), dict(self._run_counts)
 
     def record_window(self, step0: int, n_steps: int, recs: dict) -> None:
         """Unpack per-step counters stacked by a ``lax.scan`` window.
@@ -187,6 +208,7 @@ class Tracer:
         """
         if not self.wants_counters or not recs:
             return
+        self.count("host_reads")
         host = jax.device_get(recs)
         for i in range(n_steps):
             ev = {"type": "step", "step": int(step0) + i}
@@ -198,6 +220,7 @@ class Tracer:
         """Single-step counter record (the per-step host loop)."""
         if not self.wants_counters or not rec:
             return
+        self.count("host_reads")
         host = jax.device_get(rec)
         ev = {"type": "step", "step": int(step)}
         for k, v in host.items():
@@ -275,37 +298,3 @@ class Tracer:
             self.events.clear()
             self.dropped = 0
         self._epoch = time.perf_counter()
-
-
-def timed_prefix_phases(tracer: Tracer, probes: dict, iters: int = 3,
-                        warmup: int = 1) -> dict:
-    """Phase attribution of a fused pipeline by nested prefix probes.
-
-    ``probes`` maps phase name -> zero-arg thunk running the pipeline
-    *through* that phase (each probe a strict superset of the previous one,
-    e.g. gather ⊂ assembly ⊂ inference ⊂ force_reduce — see
-    :meth:`repro.core.pipeline.ForcePipeline.build_phase_probes`).  Each
-    probe's median
-    wall time over ``iters`` runs is measured after ``warmup`` compile
-    calls; successive differences are the per-phase costs, recorded as
-    ``calibrated`` spans on ``tracer`` and returned as {phase: seconds}.
-    Measured, not modeled: the last probe is the real fused driver.
-    """
-    cumul = {}
-    for name, thunk in probes.items():
-        for _ in range(warmup):
-            jax.block_until_ready(thunk())
-        ts = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            jax.block_until_ready(thunk())
-            ts.append(time.perf_counter() - t0)
-        cumul[name] = float(np.median(ts))
-    phases = {}
-    prev = 0.0
-    for name in probes:
-        phases[name] = max(cumul[name] - prev, 0.0)
-        prev = max(cumul[name], prev)
-        tracer.add_span(name, phases[name], phase=name, calibrated=True,
-                        cumulative_s=cumul[name])
-    return phases

@@ -121,10 +121,11 @@ def test_disabled_tracer_is_noop():
         pass
     tr.meta(kind="run")
     tr.instant("mark")
-    tr.add_span("derived", 0.1)
+    tr.count("host_reads")
     tr.record_window(0, 4, {"c": jnp.zeros(4)})
     tr.record_step(0, {"c": 1})
     assert tr.events == []
+    assert tr.run_totals() == ({}, {})
     assert tr.flush() is None
     assert not tr.start_capture()
 
@@ -174,10 +175,11 @@ def test_instrumented_run_bitwise_equals_uninstrumented(small_md):
             == np.asarray(st_on.velocities)).all()
     steps = [e for e in eng_on.tracer.events if e["type"] == "step"]
     assert [e["step"] for e in steps] == list(range(10))
-    cal = {e["phase"] for e in eng_on.tracer.events
-           if e.get("calibrated")}
-    assert {"scan.neighbor", "scan.classical", "scan.inference",
-            "scan.integrate"} <= cal
+    spans = [e for e in eng_on.tracer.events if e["type"] == "span"]
+    assert {e["name"] for e in spans} == {"md.window", "md.verdict",
+                                          "md.rebuild"}
+    assert [e["why"] for e in spans if e["name"] == "md.rebuild"] == [
+        "build"]
 
 
 def test_step_mode_spans_and_records(small_md, tmp_path):
@@ -233,6 +235,76 @@ def test_step_counters_cleared_between_runs(small_md):
     metas = [e for e in eng.tracer.events
              if e["type"] == "meta" and e.get("kind") == "run"]
     assert len(metas) == 2
+
+
+# -- run-loop host spans, blocking reads and per-run gauges ----------------
+
+
+@pytest.fixture(scope="module")
+def traced_engine(small_md):
+    """One engine, tracing on as the benchmark's traced runs set it (no
+    device counters), reporting into a registry of its own."""
+    system, pos, provider = small_md
+    registry = Registry()
+    tracer = Tracer(ObsConfig(enabled=True, counters=False,
+                              calibrate=False), registry=registry)
+    eng = MDEngine(system, EngineConfig(**_CFG), special_force=provider(),
+                   obs=tracer)
+    assert eng._stateful and eng.config.rebuild_every == 10
+    return eng, eng.init_state(pos, 200.0), registry
+
+
+@pytest.mark.parametrize("windows", [1, 2, 3])
+def test_traced_scan_run_publishes_its_totals(traced_engine, windows):
+    """Each run overwrites the ``md.run.*`` gauges with its own totals; a
+    window of ``rebuild_every`` steps makes 8 blocking reads: the step
+    reads of the run loop and of the segment, two verdict flags, two
+    diagnostics sums and the overflow checks of the list build and the
+    DD assembly at the window's start."""
+    eng, state, registry = traced_engine
+    eng.run(state, 10 * windows)
+    g = {k: v["value"] for k, v in registry.snapshot()["gauges"].items()}
+    assert g["md.run.steps"] == 10 * windows
+    assert g["md.run.windows"] == windows
+    assert g["md.run.host_reads"] == 8 * windows
+    for name in ("md.window", "md.verdict", "md.rebuild"):
+        assert g[f"md.run.span_s.{name}"] > 0
+    assert g["md.run.span_s.md.window"] > g["md.run.span_s.md.verdict"]
+    span_s, counts = eng.tracer.run_totals()
+    assert counts == {"host_reads": 8 * windows}
+    assert span_s["md.verdict"] == g["md.run.span_s.md.verdict"]
+
+
+def test_disabled_engine_records_counts_and_publishes_nothing(small_md):
+    system, pos, provider = small_md
+    registry = Registry()
+    tracer = Tracer(None, registry=registry)
+    eng = MDEngine(system, EngineConfig(**_CFG), special_force=provider(),
+                   obs=tracer)
+    eng.run(eng.init_state(pos, 200.0), 10)
+    assert tracer.events == []
+    assert tracer.run_totals() == ({}, {})
+    assert registry.snapshot() == {"counters": {}, "gauges": {},
+                                   "histograms": {}}
+
+
+def test_step_mode_publishes_its_totals(small_md):
+    """The per-step host loop publishes the same gauges: its reads are
+    counted through the same door (one rebuild check and one needs-rebuild
+    flag per step, and the per-step counter records)."""
+    system, pos, provider = small_md
+    registry = Registry()
+    eng = MDEngine(system, EngineConfig(loop_mode="step", **_CFG),
+                   special_force=provider(),
+                   obs=Tracer(ObsConfig(enabled=True), registry=registry))
+    eng.run(eng.init_state(pos, 200.0), 4)
+    g = {k: v["value"] for k, v in registry.snapshot()["gauges"].items()}
+    assert g["md.run.steps"] == 4 and g["md.run.windows"] == 1
+    # 2 build overflow checks, 3 step reads (run loop, segment, attempt),
+    # then per step: rebuild check, needs-rebuild flag, overflow flag and
+    # the counter record
+    assert g["md.run.host_reads"] == 2 + 3 + 4 * 4
+    assert g["md.run.span_s.md.window"] == 0.0
 
 
 # -- dd counters under scan windows and the ensemble driver (8 ranks) -------

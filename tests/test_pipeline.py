@@ -110,7 +110,6 @@ ev_tiny = ForcePipeline(model, dataclasses.replace(cfg_ov,
 _, _, d5 = ev_tiny(params, coords, st)
 out["tiny_overflow"] = int(np.asarray(d5["overflow"]))
 
-out["probe_keys"] = sorted(pipe.build_phase_probes().keys())
 print("JSON" + json.dumps(out))
 """
 
@@ -170,11 +169,6 @@ def test_overlap_trimmed_capacity_protocol(overlap_results):
     assert r["trim_df"] < 1e-5, r
     assert r["trim_de"] < 1e-5, r
     assert r["tiny_overflow"] > 0
-
-
-def test_phase_probe_stage_names(overlap_results):
-    assert overlap_results["probe_keys"] == [
-        "assembly", "force_reduce", "gather", "inference"]
 
 
 # -- in-process: config validation + deprecation shims -----------------------
