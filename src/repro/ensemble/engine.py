@@ -42,7 +42,7 @@ import numpy as np
 
 from ..md import observables
 from ..md.engine import EngineConfig, ForceProvider, MDEngine
-from ..md.neighbors import build_neighbor_list, needs_rebuild
+from ..md.neighbors import needs_rebuild
 from ..md.system import System
 from .exchange import make_exchange_fn
 from .state import ReplicaState, stack_states
@@ -130,13 +130,10 @@ class EnsembleEngine(MDEngine):
                 state, f, self._temp_table[state.ladder])
 
         self._classical_fn = jax.jit(jax.vmap(self._classical_one))
+        self._nlist_fn = jax.jit(
+            jax.vmap(self._classical_neighbor_list, in_axes=(0, None, None)),
+            static_argnums=(1, 2))
         self._integrate_fn = jax.jit(integrate_fn)
-
-    def build_nlist(self, positions):
-        cfg = self.config
-        return jax.vmap(lambda p: build_neighbor_list(
-            p, self.system.box, cfg.cutoff, cfg.neighbor_capacity, half=True,
-            skin=cfg.skin, cell_cap_scale=self._cell_cap_scale))(positions)
 
     def _check_rebuild(self, nlist, positions):
         cfg = self.config

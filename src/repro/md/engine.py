@@ -52,7 +52,7 @@ from ..health import (GuardConfig, GuardTripError, WindowVerdict,
                       dump_emergency, step_guard_trip)
 from ..obs import Tracer
 from . import observables
-from .forcefield import ForceFieldConfig, classical_energy
+from .forcefield import ForceFieldConfig, classical_forces, pair_table
 from .integrators import MDState, init_velocities, leapfrog_step, berendsen_rescale
 from .neighbors import (NeighborList, build_neighbor_list,
                         cell_capacity_scale, needs_rebuild)
@@ -194,9 +194,17 @@ class MDEngine:
     def _classical_one(self, pos, nlist):
         """Single-trajectory classical forces — the one definition both the
         scalar engine and the vmapped ensemble engine build on."""
-        e, g = jax.value_and_grad(classical_energy)(
-            pos, self.system, nlist, self.config.ff, True)
-        return e, -g
+        return classical_forces(pos, self.system, nlist, self.config.ff, True)
+
+    def _classical_neighbor_list(self, positions, capacity: int,
+                                 cell_cap_scale: float) -> NeighborList:
+        """Single-trajectory classical half list with its pair table."""
+        cfg = self.config
+        nl = build_neighbor_list(positions, self.system.box, cfg.cutoff,
+                                 capacity, half=True, skin=cfg.skin,
+                                 cell_cap_scale=cell_cap_scale)
+        return dataclasses.replace(nl, pairs=pair_table(self.system, nl,
+                                                        cfg.ff))
 
     def _integrate_one(self, state: MDState, f, thermostat_t):
         """Single-trajectory leapfrog + optional Berendsen rescale toward
@@ -214,6 +222,8 @@ class MDEngine:
     def _build_fns(self):
         cfg = self.config
         self._classical_fn = jax.jit(self._classical_one)
+        self._nlist_fn = jax.jit(self._classical_neighbor_list,
+                                 static_argnums=(1, 2))
         self._integrate_fn = jax.jit(
             lambda state, f: self._integrate_one(state, f, cfg.thermostat_t))
 
@@ -369,11 +379,10 @@ class MDEngine:
                        step=jnp.zeros((), jnp.int32), rng=rng)
 
     def build_nlist(self, positions) -> NeighborList:
-        cfg = self.config
-        return build_neighbor_list(positions, self.system.box, cfg.cutoff,
-                                   cfg.neighbor_capacity, half=True,
-                                   skin=cfg.skin,
-                                   cell_cap_scale=self._cell_cap_scale)
+        """The classical list and its pair table, one jitted program (on
+        the host side and in the window's displacement-rebuild branch)."""
+        return self._nlist_fn(positions, self.config.neighbor_capacity,
+                              self._cell_cap_scale)
 
     # -- capacity growth (mid-run overflow no longer kills the run) --------
 

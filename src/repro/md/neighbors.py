@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,9 @@ class NeighborList:
     mask: jax.Array       # (N, K) float {0,1}
     ref_positions: jax.Array  # positions at build time (for skin check)
     overflow: jax.Array   # () bool — capacity exceeded, list invalid
+    # per-slot pair constants (``forcefield.PairTable``) of a classical list,
+    # built with it; None where the list's user needs none
+    pairs: Any = None
 
     @property
     def capacity(self) -> int:

@@ -2,14 +2,17 @@
 reproduce the per-step host loop exactly, the Fig.-9 stage timers must all
 be written in step mode, capacity overflow must grow instead of killing the
 run, and the redundant step-0 rebuild stays gone."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core import DeepmdForceProvider
 from repro.dp import DPModel, paper_dpa1_config
-from repro.md import (EngineConfig, MDEngine, build_solvated_protein,
-                      mark_nn_group)
+from repro.md import (EngineConfig, MDEngine, build_neighbor_list,
+                      build_solvated_protein, classical_forces, mark_nn_group)
 
 
 @pytest.fixture(scope="module")
@@ -79,6 +82,34 @@ def test_displacement_rebuilds_inside_scan(small_system):
     assert (eng_s.diagnostics["displacement_rebuilds"]
             == eng_p.diagnostics["displacement_rebuilds"])
     assert float(jnp.abs(st_s.positions - st_p.positions).max()) <= 1e-6
+
+
+def test_displacement_rebuild_in_window_uses_rebuilt_pair_table(
+        small_system):
+    """A list the displacement check rejects is rebuilt inside the window,
+    pair table and all: the step's forces are those of a fresh list.  The
+    stale list's table drops every pair, so forces from it would differ."""
+    system, pos = small_system[0], small_system[1]
+    cfg = EngineConfig(rebuild_every=1000, **_CFG)
+    eng = MDEngine(system, cfg)
+    state = eng.init_state(pos, 200.0)
+    nl = eng.build_nlist(pos)
+    stale = dataclasses.replace(
+        nl, ref_positions=pos + cfg.skin,
+        pairs=dataclasses.replace(nl.pairs,
+                                  mask=jnp.zeros_like(nl.pairs.mask)))
+    (new, nl_out, _, flags, e_cl, _), _ = eng._window_fn(1)(state, stale,
+                                                            None)
+    assert int(flags["rebuilds"]) == 1
+    assert float(nl_out.pairs.mask.sum()) > 0
+    fresh = build_neighbor_list(pos, system.box, cfg.cutoff,
+                                cfg.neighbor_capacity, half=True,
+                                skin=cfg.skin)
+    e_ref, f_ref = classical_forces(pos, system, fresh, cfg.ff)
+    assert abs(float(e_cl - e_ref)) <= 1e-6 * abs(float(e_ref)) + 1e-4
+    np.testing.assert_allclose(np.asarray(new.forces), np.asarray(f_ref),
+                               rtol=1e-5,
+                               atol=1e-5 * float(jnp.abs(f_ref).max()))
 
 
 def test_step_mode_writes_all_timers(small_system):

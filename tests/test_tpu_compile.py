@@ -3,9 +3,10 @@
 No chip is needed: the TPU compiler is handed a ``v5e:2x2`` topology and
 compiles each kernel for one of its chips at the paper's widths (N = 1024
 atoms, K = 128 neighbour lanes, M = 128 embedding width, H = 256 attention
-hidden width, L = 3 layers).  A compile that passes here says the kernel's
-tiling, VMEM use and lowering are accepted by the chip's compiler; it says
-nothing about results or speed.
+hidden width, L = 3 layers), and the classical force step at the benchmark
+cell's shapes.  A compile that passes here says the kernel's tiling, VMEM
+use and lowering are accepted by the chip's compiler; it says nothing about
+results or speed.
 
 The topology is described inside a module fixture (never at import): only
 one process at a time may load the TPU library, and every test worker
@@ -22,6 +23,9 @@ import pytest
 from repro.kernels.cell_gather import cell_filter
 from repro.kernels.env_mat import env_mat
 from repro.kernels.nbr_attn import nbr_attention_stack
+from repro.md.forcefield import ForceFieldConfig, PairTable, classical_forces
+from repro.md.neighbors import NeighborList
+from repro.md.system import System, Topology
 
 N, K, M, H, L = 1024, 128, 128, 256, 3
 RC_SMTH, RC = 0.5, 0.8
@@ -97,3 +101,34 @@ def test_nbr_attention_stack_fwd_bwd_compiles(one_chip, dtype, heads):
     text = _compiled_text(fwd_bwd, g, *planes, *w_in, w_out, *ln)
     assert "tpu_custom_call" in text
     assert "nbr_attn_stack_fwd" in text and "nbr_attn_stack_bwd" in text
+
+
+def test_classical_forces_compile_without_padded_pair_buffers(one_chip):
+    """The engine's classical step at the benchmark cell's shapes (16,063
+    atoms, a 96-wide half list with its pair table, 16 exclusion columns,
+    the chain's bonds and angles) holds no per-slot three-vector, whose 3
+    would sit on the 128-lane axis, and compiles to far less temp memory
+    than the autodiff form's 2.39 GB."""
+    n, k, e, nb, na = 16063, 96, 16, 4095, 4094
+    f32 = lambda *shape: _spec(shape, one_chip)
+    i32 = lambda *shape: _spec(shape, one_chip, jnp.int32)
+    topology = Topology(
+        bonds=i32(nb, 2), bond_params=f32(nb, 2), bond_mask=f32(nb),
+        angles=i32(na, 3), angle_params=f32(na, 2), angle_mask=f32(na),
+        dihedrals=i32(1, 4), dihedral_params=f32(1, 3), dihedral_mask=f32(1),
+        exclusions=i32(n, e))
+    system = System(box=f32(3), types=i32(n), masses=f32(n), charges=f32(n),
+                    lj_sigma=f32(4), lj_epsilon=f32(4), topology=topology,
+                    nn_mask=f32(n))
+    nlist = NeighborList(idx=i32(n, k), mask=f32(n, k),
+                         ref_positions=f32(n, 3),
+                         overflow=_spec((), one_chip, jnp.bool_),
+                         pairs=PairTable(*[f32(n, k)] * 4))
+    cfg = ForceFieldConfig(cutoff=1.2)
+    compiled = jax.jit(
+        lambda pos, s, nl: classical_forces(pos, s, nl, cfg)).lower(
+            f32(n, 3), system, nlist).compile()
+    text = compiled.as_text()
+    assert "f32[16063,96,3]" not in text
+    assert "f32[1542048,3]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.39e9
